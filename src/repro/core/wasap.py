@@ -583,12 +583,12 @@ class WASAPTrainer:
                     )
 
                 # jitted-call boundary: the whole epoch's sync rounds are one
-                # device call; registered outputs are blocked on at span
-                # close (the code below blocks on the same values anyway)
+                # device call; the span times its dispatch and blocks on
+                # nothing (the loop first blocks after master evolution)
                 with obs.span(
                     "wasap.sync_rounds", rounds=rounds, h=h,
                     elastic=weights is not None,
-                ) as sr_sp:
+                ):
                     if self.step_retries:
                         out = retry_step(
                             run_epoch,
@@ -604,7 +604,6 @@ class WASAPTrainer:
                     else:
                         params, opt_state, loss_sums = out
                         probe_dev = None
-                    sr_sp.block_on(loss_sums)
                 gstep += steps
                 # master topology evolution on the averaged model; momentum
                 # is re-aligned (RetainValidUpdates semantics for velocity)

@@ -203,9 +203,10 @@ def bsmm(
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _espmm_core(out_dim: int, chunk, x2, values, topo: ElemTopoArrays):
-    yT = coo_matmul_T(
-        x2.T, values, topo.rows, topo.cols, out_dim, chunk=chunk
-    )
+    with jax.named_scope("espmm_fwd"):
+        yT = coo_matmul_T(
+            x2.T, values, topo.rows, topo.cols, out_dim, chunk=chunk
+        )
     return yT.T
 
 
@@ -220,12 +221,14 @@ def _espmm_core_bwd(out_dim, chunk, res, dy):
     dyT = dy.T
     # dX over the row-sorted dual order: segment ids (rows_r) sorted, the
     # values gathered through perm_r from their canonical slots
-    dxT = coo_matmul_T(
-        dyT, values[topo.perm_r], topo.cols_r, topo.rows_r, in_dim,
-        chunk=chunk,
-    )
+    with jax.named_scope("espmm_dx"):
+        dxT = coo_matmul_T(
+            dyT, values[topo.perm_r], topo.cols_r, topo.rows_r, in_dim,
+            chunk=chunk,
+        )
     # dW in canonical slot order
-    dv = coo_dw(x2.T, dyT, topo.rows, topo.cols, chunk=chunk)
+    with jax.named_scope("espmm_dw"):
+        dv = coo_dw(x2.T, dyT, topo.rows, topo.cols, chunk=chunk)
     dtopo = ElemTopoArrays(*(_float0_zeros(t) for t in topo))
     return dxT.T.astype(x2.dtype), dv.astype(values.dtype), dtopo
 

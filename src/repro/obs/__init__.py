@@ -1,5 +1,6 @@
-"""Unified telemetry substrate: metrics registry, span tracing, profiling
-hooks, exporters (DESIGN.md §11).
+"""Unified telemetry substrate: metrics registry, span tracing (marked in
+the ``jax.profiler`` trace too), compile counters, exporters (DESIGN.md
+§11).
 
 The one import every instrumented subsystem makes::
 
@@ -7,7 +8,7 @@ The one import every instrumented subsystem makes::
 
     with obs.span("train.epoch", epoch=epoch) as sp:
         params, losses = segment(...)
-        sp.block_on((params, losses))   # close waits for device results
+        sp.block_on((params, losses))   # only where the code blocks anyway
 
 Hard rules, enforced by the §10 auditor's ``obs-in-jit`` lint rule:
 instrumentation lives host-side *between* jitted calls, never inside a
@@ -50,11 +51,7 @@ from repro.obs.metrics import (
     RollingWindow,
     default_registry,
 )
-from repro.obs.profiling import (
-    profile_trace,
-    record_compile_counts,
-    sample_device_memory,
-)
+from repro.obs.profiling import record_compile_counts
 from repro.obs.probes import (
     record_snapshot,
     set_snapshot_transform,
@@ -93,7 +90,7 @@ __all__ = [
     "configure", "shutdown", "trace_to", "current_tracer",
     "current_span_name",
     # profiling
-    "profile_trace", "sample_device_memory", "record_compile_counts",
+    "record_compile_counts",
     # export
     "prometheus_text", "read_events", "validate_events",
     "summarize_events", "format_summary",

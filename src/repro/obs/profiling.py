@@ -1,74 +1,21 @@
-"""Profiling hooks: opt-in ``jax.profiler`` capture, device-memory gauges,
-and compile-event counters (DESIGN.md §11).
+"""Compile-event counters (DESIGN.md §11).
 
-Off-accelerator, CPU jaxlib reports no ``memory_stats()``: the memory
-gauges are then simply not set, never faked. Compile counters are *fed* from the
-subsystems' existing surfaces (``xl.stream.compile_counts()``, the serving
-``_JitCache`` stats) rather than hooked into jax internals, so they stay
-exact and host-side.
+Compile counters are *fed* from the subsystems' existing surfaces
+(``xl.stream.compile_counts()``, the serving ``_JitCache`` stats) rather
+than hooked into jax internals, so they stay exact and host-side.
+``jax.profiler`` captures need no wrapper here: every ``obs.span`` is
+marked in the profiler trace by name (``obs.trace``).
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Dict, Mapping, Optional
+from typing import Mapping, Optional
 
-from repro.obs import _state, trace
+from repro.obs import _state
 from repro.obs.metrics import MetricsRegistry, default_registry
 
 __all__ = [
-    "profile_trace",
-    "sample_device_memory",
     "record_compile_counts",
 ]
-
-
-@contextlib.contextmanager
-def profile_trace(logdir: str, name: str = "profile"):
-    """Capture a ``jax.profiler`` trace around a block, bracketed by obs
-    point events so the capture window is visible in the span timeline."""
-    import jax
-
-    trace.point("profile.start", name=name, logdir=str(logdir))
-    jax.profiler.start_trace(str(logdir))
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        trace.point("profile.stop", name=name, logdir=str(logdir))
-
-
-def sample_device_memory(
-    registry: Optional[MetricsRegistry] = None,
-    emit_point: bool = False,
-) -> Dict[str, float]:
-    """Read per-device memory stats + live-buffer count into gauges.
-
-    Returns what was read (empty when the backend exposes nothing, e.g.
-    CPU jaxlib). Cheap enough for per-step sampling, but intended for
-    epoch/round boundaries.
-    """
-    if not _state.is_enabled():
-        return {}
-    import jax
-
-    reg = registry if registry is not None else default_registry()
-    out: Dict[str, float] = {}
-    for dev in jax.local_devices():
-        stats = dev.memory_stats()
-        if not stats:
-            continue
-        dev_id = str(dev.id)
-        for key in ("bytes_in_use", "peak_bytes_in_use", "largest_alloc_size"):
-            if key in stats:
-                val = float(stats[key])
-                reg.gauge(f"device_{key}", device=dev_id).set(val)
-                out[f"device_{key}{{device={dev_id}}}"] = val
-    live = len(jax.live_arrays())
-    reg.gauge("device_live_buffers").set(float(live))
-    out["device_live_buffers"] = float(live)
-    if emit_point and out:
-        trace.point("device_memory", **{k: v for k, v in out.items()})
-    return out
 
 
 def record_compile_counts(

@@ -1,5 +1,6 @@
 """Span tracing: context-manager spans forming a tree, emitted as JSONL
-events with monotonic timestamps (DESIGN.md §11).
+events with monotonic timestamps (DESIGN.md §11), and marked in the
+``jax.profiler`` trace under the same name.
 
 The span taxonomy mirrors the repo's execution structure — training:
 ``run → epoch → segment/shard-stream/sync-round → jitted-call boundary``;
@@ -7,21 +8,27 @@ serving: ``request → queue → prefill → decode steps``. Every event carries
 ``time.perf_counter()`` timestamps (monotonic, high resolution, process
 local) — never wall clock, so spans order correctly across clock steps.
 
+Every span also holds a ``jax.profiler.TraceAnnotation`` of its name open
+for its duration, so a profiler capture shows the program's own phases on
+the device trace's clock, around the device ops and idle gaps they cause.
+The annotation costs a flag check while no capture runs.
+
 **The PR-1 timing lesson**: JAX dispatch is asynchronous, so a span that
 closes right after a jitted call has measured *dispatch*, not *work*.
 Spans therefore carry an explicit ``block_on(x)`` hook: objects registered
 with it are ``jax.block_until_ready``-ed at span close, *before* the close
-timestamp is read. Instrumentation sites register exactly the device
-values whose completion the span claims to time — and nothing else, so
-tracing never introduces synchronization a disabled run wouldn't have at
-that point (sites only register values the surrounding code blocks on
-anyway).
+timestamp is read. A site registers only values the surrounding code
+blocks on at that same point anyway: a span never blocks where the
+untraced run does not. Where the code blocks later, the span times the
+dispatch, and the device work is read from the profiler trace's device
+lines.
 
-When no tracer is installed — or inside ``obs.disabled()`` — ``span()``
-and ``point()`` return/are singleton no-ops: no ``Span`` object, no event
-dict, no sample is allocated (asserted by the ``_state.debug_allocs``
-counter in tests). Instrumentation can therefore stay permanently in the
-hot loops.
+With no tracer installed, ``span()`` returns the bare profiler annotation
+(``set``/``block_on`` are no-ops) and ``point()`` does nothing: no
+``Span``, no event dict, no sample is allocated (asserted by the
+``_state.debug_allocs`` counter in tests). Inside ``obs.disabled()``
+``span()`` returns a singleton no-op that marks nothing. Instrumentation
+can therefore stay permanently in the hot loops.
 
 Event schema (one JSON object per line; ``ev`` discriminates):
 
@@ -43,6 +50,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, IO, List, Optional, Tuple, Union
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import _state
 
@@ -82,7 +91,7 @@ class Span:
     """One open span; use via ``with obs.span(name, **attrs) as sp:``."""
 
     __slots__ = ("_tracer", "name", "id", "parent", "t0", "attrs",
-                 "_block", "_token")
+                 "_block", "_token", "_mark")
 
     def __init__(self, tracer: "Tracer", name: str, parent: Optional[int],
                  span_id: int, attrs: Optional[Dict[str, Any]]):
@@ -94,6 +103,7 @@ class Span:
         self.t0 = 0.0
         self._block: List[Any] = []
         self._token = None
+        self._mark = TraceAnnotation(name)
 
     def set(self, **attrs: Any) -> "Span":
         """Attach attributes discovered mid-span (loss, token counts...)."""
@@ -107,6 +117,7 @@ class Span:
         return obj
 
     def __enter__(self) -> "Span":
+        self._mark.__enter__()
         self.t0 = self._tracer.clock()
         self._token = _span_stack.set(
             _span_stack.get() + ((self.id, self.name),)
@@ -117,6 +128,7 @@ class Span:
         if self._block:
             _block_until_ready(self._block)
         t1 = self._tracer.clock()
+        self._mark.__exit__(exc_type, exc, tb)
         if self._token is not None:
             _span_stack.reset(self._token)
         if exc_type is not None:
@@ -129,8 +141,8 @@ class Span:
 
 
 class _NoopSpan:
-    """Singleton returned when tracing is off: every method is a no-op and
-    allocates nothing."""
+    """Singleton returned inside ``obs.disabled()``: every method is a
+    no-op and allocates nothing."""
 
     __slots__ = ()
 
@@ -148,6 +160,19 @@ class _NoopSpan:
 
 
 NOOP_SPAN = _NoopSpan()
+
+
+class _ProfilerSpan(TraceAnnotation):
+    """The span when no tracer is installed: only the profiler annotation,
+    with the ``Span`` surface as no-ops."""
+
+    __slots__ = ()
+
+    def set(self, **attrs: Any) -> "_ProfilerSpan":
+        return self
+
+    def block_on(self, obj: Any) -> Any:
+        return obj
 
 
 class Tracer:
@@ -256,11 +281,13 @@ def current_tracer() -> Optional[Tracer]:
 
 
 def span(name: str, **attrs: Any):
-    """Open a span under the current one. No tracer / disabled → no-op
-    singleton (zero allocations)."""
-    t = _tracer
-    if t is None or not _state.is_enabled():
+    """Open a span under the current one. No tracer → the profiler
+    annotation alone (no obs-owned allocation); disabled → no-op singleton."""
+    if not _state.is_enabled():
         return NOOP_SPAN
+    t = _tracer
+    if t is None:
+        return _ProfilerSpan(name)
     return t.span(name, attrs if attrs else None)
 
 

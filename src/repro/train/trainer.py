@@ -566,12 +566,11 @@ class SequentialTrainer:
                         self.key
                     )
 
-                # jitted-call boundary: the span registers the segment's
-                # outputs and blocks on them only at close, so the duration
-                # covers device compute without adding a sync the
-                # uninstrumented run would not pay (it blocks on the same
-                # values below, before reading epoch_seconds)
-                with obs.span("train.segment", steps=steps) as seg_sp:
+                # jitted-call boundary: the span times the dispatch and
+                # blocks on nothing (the loop first blocks after evolution
+                # is dispatched); the segment's device time is read from
+                # the profiler trace's device lines
+                with obs.span("train.segment", steps=steps):
                     if self.step_retries:
                         out = retry_step(
                             run_segment,
@@ -585,7 +584,6 @@ class SequentialTrainer:
                     else:
                         params, opt_state, self.key, losses = out
                         probe_dev = None
-                    seg_sp.block_on(losses)
                 gstep += steps
                 model.set_params(params)
                 self.opt_state = opt_state
@@ -604,29 +602,32 @@ class SequentialTrainer:
                         "train.prune", epoch=epoch, n_params=model.n_params
                     )
                 if epoch < tc.epochs - 1 and tc.evolve and sparse_impl:
-                    if device_evo:
-                        topo, params, opt_state = self._evolve_device(
-                            topo, params, opt_state
-                        )
-                        model.set_params(params)
-                        self.opt_state = opt_state
-                        topo_dirty = True
-                    else:
-                        params, opt_state, topo = self._host_topology_op(
-                            topo, topo_dirty, self._evolve
-                        )
-                        topo_dirty = False
-                    obs.point("train.evolve", epoch=epoch, device=device_evo)
+                    with obs.span(
+                        "train.evolve", epoch=epoch, device=device_evo
+                    ):
+                        if device_evo:
+                            topo, params, opt_state = self._evolve_device(
+                                topo, params, opt_state
+                            )
+                            model.set_params(params)
+                            self.opt_state = opt_state
+                            topo_dirty = True
+                        else:
+                            params, opt_state, topo = self._host_topology_op(
+                                topo, topo_dirty, self._evolve
+                            )
+                            topo_dirty = False
                 # dispatch is async — wait for the epoch's device work so
                 # epoch_seconds measures compute, not enqueue
                 jax.block_until_ready((params, losses))
                 dt = time.perf_counter() - t0
                 if (epoch + 1) % tc.eval_every == 0 or epoch == tc.epochs - 1:
-                    acc = evaluate(
-                        model, self.data.x_test, self.data.y_test,
-                        params=params, topo_arrays=topo,
-                    )
-                    obs.point("train.eval", epoch=epoch, acc=float(acc))
+                    with obs.span("train.eval", epoch=epoch) as ev_sp:
+                        acc = evaluate(
+                            model, self.data.x_test, self.data.y_test,
+                            params=params, topo_arrays=topo,
+                        )
+                        ev_sp.set(acc=float(acc))
                 else:
                     acc = float("nan")
                 if probe_dev is not None:
@@ -693,7 +694,8 @@ class SequentialTrainer:
                 losses = []
                 # one span per epoch's worth of per-batch dispatches — NOT
                 # per minibatch, which is exactly the dispatch-bound hot loop
-                # this legacy mode exists to measure
+                # this legacy mode exists to measure; like the fused
+                # segment's it blocks on nothing
                 with obs.span("train.segment", mode="per_batch") as seg_sp:
                     for xb, yb in loader.epoch(epoch):
                         self.key, sub = jax.random.split(self.key)
@@ -725,18 +727,18 @@ class SequentialTrainer:
                         losses.append(loss)
                         gstep += 1
                     seg_sp.set(steps=len(losses))
-                    seg_sp.block_on(params)
                 model.set_params(params)
                 # topology phase (host)
                 self._importance_prune(epoch)
                 if epoch < tc.epochs - 1:  # paper: no evolution after final
-                    self._evolve()
-                    obs.point("train.evolve", epoch=epoch, device=False)
+                    with obs.span("train.evolve", epoch=epoch, device=False):
+                        self._evolve()
                 jax.block_until_ready(model.params())
                 dt = time.perf_counter() - t0
                 if (epoch + 1) % tc.eval_every == 0 or epoch == tc.epochs - 1:
-                    acc = evaluate(model, self.data.x_test, self.data.y_test)
-                    obs.point("train.eval", epoch=epoch, acc=float(acc))
+                    with obs.span("train.eval", epoch=epoch) as ev_sp:
+                        acc = evaluate(model, self.data.x_test, self.data.y_test)
+                        ev_sp.set(acc=float(acc))
                 else:
                     acc = float("nan")
                 self.history["epoch"].append(epoch)
@@ -962,10 +964,12 @@ class XLTrainer:
                             gstep += 1
                     evo_stats = None
                     if epoch < tc.epochs - 1 and tc.evolve:
-                        evo_stats = evolve_model_streamed(
-                            self.state, tc.zeta, self.rng
-                        )
-                        obs.point("train.evolve", epoch=epoch, device=False)
+                        with obs.span(
+                            "train.evolve", epoch=epoch, device=False
+                        ):
+                            evo_stats = evolve_model_streamed(
+                                self.state, tc.zeta, self.rng
+                            )
                     if tc.probe and probe_batch is not None:
                         layer_stats = self.executor.probe_stats(*probe_batch)
                         churn = None
@@ -982,8 +986,11 @@ class XLTrainer:
                     dt = time.perf_counter() - t0
                     if (epoch + 1) % tc.eval_every == 0 \
                             or epoch == tc.epochs - 1:
-                        acc = self.evaluate(self.data.x_test, self.data.y_test)
-                        obs.point("train.eval", epoch=epoch, acc=float(acc))
+                        with obs.span("train.eval", epoch=epoch) as ev_sp:
+                            acc = self.evaluate(
+                                self.data.x_test, self.data.y_test
+                            )
+                            ev_sp.set(acc=float(acc))
                     else:
                         acc = float("nan")
                     self.history["epoch"].append(epoch)

@@ -242,3 +242,69 @@ def test_espmm_auto_dispatch_and_unknown_impl():
     )
     with pytest.raises(ValueError):
         ops.espmm(x, vals, t, 24, impl="nope")
+
+
+# ---------------------------------------------------------------------------
+# pass scopes in the compiled train step (what a profiler trace reads)
+# ---------------------------------------------------------------------------
+
+PASS_SCOPES = ("espmm_fwd", "espmm_dx", "espmm_dw")
+
+
+def _segment_hlo(make):
+    """Compiled HLO text of a tiny custom-VJP epoch segment built by
+    ``make(config, opt)``."""
+    from repro.models.mlp import SparseMLP, SparseMLPConfig
+    from repro.optim.sgd import MomentumSGD
+
+    cfg = SparseMLPConfig(
+        layer_dims=(48, 64, 32, 4), epsilon=6, activation="all_relu",
+        alpha=0.6, dropout=0.3, impl="element", element_impl="custom",
+        spmm_chunk=64,
+    )
+    model, opt = SparseMLP(cfg, seed=0), MomentumSGD(momentum=0.9)
+    params = model.params()
+    args = (params, opt.init(params), model.topo_arrays(),
+            jnp.zeros((64, 48)), jnp.zeros((64,), jnp.int32),
+            jnp.zeros((2, 16), jnp.int32), jnp.zeros((2,), jnp.float32),
+            jax.random.PRNGKey(0))
+    return make(cfg, opt).lower(*args).compile().as_text()
+
+
+def test_segment_ops_carry_the_pass_scopes():
+    """Each espmm pass names at least one op of the compiled segment in its
+    ``op_name`` metadata, as the last pass name on the op's path."""
+    import re
+
+    from repro.train.trainer import make_segment_fn
+
+    scopes = {}
+    for path in re.findall(r'op_name="([^"]*)"', _segment_hlo(make_segment_fn)):
+        found = re.findall(r"\b(espmm_(?:fwd|dx|dw))\b", path)
+        if found:
+            scopes[found[-1]] = scopes.get(found[-1], 0) + 1
+    assert set(scopes) == set(PASS_SCOPES) and min(scopes.values()) >= 1
+
+
+def test_pass_scopes_leave_the_program_unchanged(monkeypatch):
+    """The scopes change op metadata only: without them the compiled
+    segment is the same op for op."""
+    import contextlib
+    import re
+
+    from repro.train.trainer import make_segment_program
+
+    def ops_only(hlo):
+        lines = [l for l in hlo.splitlines()
+                 if l.startswith(("%", "ENTRY", " ", "}"))]
+        return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+
+    def fresh(cfg, opt):
+        return jax.jit(make_segment_program(cfg, opt))
+
+    scoped = _segment_hlo(fresh)
+    assert "espmm_dw" in scoped
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    plain = _segment_hlo(fresh)
+    assert "espmm_dw" not in plain
+    assert ops_only(scoped) == ops_only(plain)

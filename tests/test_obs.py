@@ -236,7 +236,38 @@ def test_disabled_span_is_the_noop_singleton(tmp_path):
             assert sp.set(a=1) is sp
             assert sp.block_on("v") == "v"
     obs.shutdown()
-    assert obs.span("no_tracer_installed") is NOOP_SPAN
+    # no tracer: only the profiler annotation, no obs-owned allocation, and
+    # the Span surface still honoured
+    a0 = obs.debug_allocs()
+    with obs.span("no_tracer_installed", k=1) as sp:
+        assert sp.set(a=1) is sp
+        assert sp.block_on("v") == "v"
+    assert obs.debug_allocs() == a0
+    assert obs.span("no_tracer_installed") is not NOOP_SPAN
+
+
+@pytest.mark.parametrize("mode", ["no_tracer", "disabled"])
+def test_span_allocates_nothing_without_a_tracer(mode, tmp_path):
+    """With no JSONL tracer, and under ``obs.disabled()`` with one, a hot
+    loop of spans makes no obs-owned allocation and writes no event."""
+    obs.shutdown()
+
+    def loop():
+        a0 = obs.debug_allocs()
+        for i in range(1000):
+            with obs.span("hot", i=i) as sp:
+                sp.set(x=i)
+                sp.block_on(i)
+        return obs.debug_allocs() - a0
+
+    if mode == "no_tracer":
+        assert loop() == 0
+        return
+    with obs.trace_to(str(tmp_path / "t.jsonl")) as t:
+        before = t.events_written
+        with obs.disabled():
+            assert loop() == 0
+        assert t.events_written == before
 
 
 def test_control_registry_ignores_disabled():
@@ -320,11 +351,73 @@ def test_trainer_emits_span_taxonomy(tmp_path):
     events = obs.read_events(path)
     assert obs.validate_events(events) == []
     span_names = {e["name"] for e in events if e["ev"] == "span"}
-    assert {"train.run", "train.epoch", "train.segment"} <= span_names
+    assert {"train.run", "train.epoch", "train.segment", "train.evolve",
+            "train.eval"} <= span_names
     epochs = [e for e in events if e.get("name") == "train.epoch"]
     assert len(epochs) == 2
     run_span = next(e for e in events if e.get("name") == "train.run")
     assert all(e["parent"] == run_span["id"] for e in epochs)
+    epoch_ids = {e["id"] for e in epochs}
+    inner = [e for e in events if e["ev"] == "span" and e["name"] in
+             ("train.segment", "train.evolve", "train.eval")]
+    assert all(e["parent"] in epoch_ids for e in inner)
+    evals = [e for e in inner if e["name"] == "train.eval"]
+    assert len(evals) == 2 and all(0 <= e["attrs"]["acc"] <= 1 for e in evals)
+
+
+def _profiler_host_events(trace_dir):
+    """Host events of the newest ``.xplane.pb`` under ``trace_dir`` as
+    {name: [(start_ns, end_ns), ...]}."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    files = sorted(glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb"))
+    assert files, "the profiler wrote no trace"
+    out = {}
+    for plane in ProfileData.from_file(files[-1]).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (int(e.start_ns), int(e.start_ns + e.duration_ns)))
+    return out
+
+
+def test_trainer_spans_reach_the_profiler_trace(tmp_path):
+    """With no JSONL tracer, a ``jax.profiler`` capture of a two-epoch run
+    holds the epoch loop's spans as host events, the segment, evolution
+    and evaluation inside an epoch."""
+    import jax
+
+    from repro.data import datasets
+    from repro.models.mlp import SparseMLP, SparseMLPConfig
+    from repro.train.trainer import SequentialTrainer, TrainerConfig
+
+    obs.shutdown()
+    data = datasets.load("fashionmnist", scale=0.02, seed=0)
+    cfg = SparseMLPConfig(
+        layer_dims=(data.n_features, 32, 16, data.n_classes),
+        epsilon=8, activation="all_relu", alpha=0.6, dropout=0.0,
+        impl="element",
+    )
+    tc = TrainerConfig(epochs=2, batch_size=32, lr=0.01, zeta=0.2, seed=0)
+    trainer = SequentialTrainer(SparseMLP(cfg, seed=0), data, tc)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        trainer.run()
+    finally:
+        jax.profiler.stop_trace()
+    host = _profiler_host_events(tmp_path)
+    epochs = host.get("train.epoch", [])
+    assert len(epochs) == 2
+    for name, count in (("train.segment", 2), ("train.evolve", 1),
+                        ("train.eval", 2)):
+        spans = host.get(name, [])
+        assert len(spans) == count, name
+        assert all(any(s0 <= s and e <= e0 for s0, e0 in epochs)
+                   for s, e in spans), name
 
 
 def test_gateway_emits_request_and_queue_spans(tmp_path):
