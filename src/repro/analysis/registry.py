@@ -2,7 +2,7 @@
 repo, declared as a machine-checkable contract next to the code it audits.
 
 Each hot-path subsystem (``train.trainer``, ``core.wasap``, ``xl.stream``,
-``serve.engine``, ``launch.steps``) exposes an ``analysis_programs()`` hook
+``serve.engine``, ``launch.steps``, ``kernels.sorted_segment_sum``) exposes an ``analysis_programs()`` hook
 returning :class:`ProgramSpec` entries. A spec names a jitted program, knows
 how to build it at a representative-but-CI-sized scale, and declares a
 :class:`Contract` — what the jaxpr may contain, what the compiled HLO must
@@ -37,6 +37,7 @@ HOOK_MODULES: Tuple[str, ...] = (
     "repro.xl.stream",
     "repro.serve.engine",
     "repro.launch.steps",
+    "repro.kernels.sorted_segment_sum",
 )
 
 

@@ -23,11 +23,15 @@ connections move.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import obs
+from repro.kernels import sorted_segment_sum as _sss
 
 __all__ = [
     "BlockMeta",
@@ -279,14 +283,16 @@ class ElemTopoArrays(NamedTuple):
     Canonical order is sorted by (col, row) — ``cols`` is non-decreasing, so
     the forward/dW passes are sorted segment reductions. The ``*_r`` fields
     are the same connections re-sorted by (row, col) for the hand-derived dX
-    backward pass (``rows_r`` non-decreasing — sorted segment ids, no XLA
-    scatter anywhere); ``perm_r[j]`` maps row-ordered slot j back to the
-    canonical slot owning its value. ``first_col``/``first_row`` flag segment
-    boundaries (1 where the sort key changes), mirroring ``BlockTopoArrays``:
-    the XLA-path kernels use ``indices_are_sorted`` segment sums and don't
-    read them, but a Pallas element kernel needs them for its first-visit
-    output-tile zeroing exactly like the block kernels — the layouts are
-    kept identical so the two granularities stay drop-in interchangeable.
+    backward pass (``rows_r`` non-decreasing — sorted segment ids, so no
+    unsorted scatter); ``perm_r[j]`` maps row-ordered slot j back to the
+    canonical slot owning its value. Sorted is not scatter-free: on the TPU,
+    XLA's sorted ``segment_sum`` is a serialized scatter-add, which is why
+    the forward and dX segment sums run the one-hot MXU kernel there
+    (``kernels.sorted_segment_sum``, DESIGN.md §1b). ``first_col``/
+    ``first_row`` flag segment boundaries (1 where the sort key changes),
+    mirroring ``BlockTopoArrays``: the element passes read the sorted ids
+    themselves and not these flags, but the layouts are kept identical so
+    the two granularities stay drop-in interchangeable.
     """
 
     rows: jax.Array
@@ -490,8 +496,16 @@ def coo_matmul_T(
     non-decreasing — the canonical (col, row) order for the forward
     (gather rows, segment cols) and the row-sorted dual order for dX
     (gather cols_r, segment rows_r) both guarantee it — so every chunk's
-    ``segment_sum`` is one sorted linear pass, no scatter. Peak intermediate
-    is the (chunk, B) contribution slab; nnz is walked by a ``lax.scan``.
+    reduction is a sorted segment sum. Peak intermediate is the (chunk, B)
+    contribution slab; nnz is walked by a ``lax.scan``.
+
+    The segment sum is chosen from what the call can observe (DESIGN.md
+    §1b): on the TPU, where XLA's sorted ``segment_sum`` is a scatter-add
+    that updates one accumulator row per slot in turn, an f32 reduction of
+    a shape the kernel takes (``kernels.sorted_segment_sum.fits``) runs the
+    one-hot MXU kernel; every other backend and shape keeps
+    ``segment_sum``. The ``espmm.segment_reduce`` counter counts each
+    traced call by the path it takes on the default backend.
 
     ``acc`` (optional, (n_segments, B)) is a carry-in accumulator: the result
     is ``acc`` plus this call's reduction, added chunk-by-chunk in the same
@@ -499,7 +513,8 @@ def coo_matmul_T(
     (``kernels.ops.xl_shard_acc``, DESIGN.md §7) threads one accumulator
     through a connection-shard stream; when shard boundaries are multiples of
     ``chunk``, the chunk partition — and therefore the f32 addition order —
-    is identical to one in-core call over the concatenated shards.
+    is identical to one in-core call over the concatenated shards, on
+    either path.
     """
     nnz = int(values.shape[0])
     B = srcT.shape[-1]
@@ -507,6 +522,46 @@ def coo_matmul_T(
     if nnz == 0:
         return acc if acc is not None else jnp.zeros((n_segments, B), dtype)
     chunk = spmm_chunk_for(B, nnz, chunk)
+    args = (srcT, values, gather_idx, segment_idx, acc)
+    xla = functools.partial(_coo_matmul_T_xla, n_segments=n_segments, chunk=chunk)
+    if not _sss.fits(n_segments, B, chunk, dtype):
+        _count_segment_reduce("xla")
+        return xla(*args)
+    _count_segment_reduce("pallas" if jax.default_backend() == "tpu" else "xla")
+    pallas = functools.partial(
+        _coo_matmul_T_pallas, n_segments=n_segments, chunk=chunk
+    )
+    return jax.lax.platform_dependent(*args, tpu=pallas, default=xla)
+
+
+def _count_segment_reduce(path: str) -> None:
+    obs.default_registry().counter("espmm.segment_reduce", path=path).inc()
+
+
+def _chunked(gather_idx, segment_idx, values, *, n_segments, chunk):
+    """The slots as (n_chunks, chunk) scan slices, the last chunk padded
+    with slots of segment id ``n_segments`` (dropped by the reduction, and
+    >= every real id so per-chunk sortedness holds) and value 0."""
+    nnz = int(values.shape[0])
+    n_chunks = -(-nnz // chunk)
+    pad = n_chunks * chunk - nnz
+    g_p = jnp.concatenate([gather_idx, jnp.zeros((pad,), gather_idx.dtype)])
+    s_p = jnp.concatenate(
+        [segment_idx, jnp.full((pad,), n_segments, segment_idx.dtype)]
+    )
+    v_p = jnp.concatenate([values, jnp.zeros((pad,), values.dtype)])
+    return (
+        g_p.reshape(n_chunks, chunk),
+        s_p.reshape(n_chunks, chunk),
+        v_p.reshape(n_chunks, chunk),
+    )
+
+
+def _coo_matmul_T_xla(srcT, values, gather_idx, segment_idx, acc, *, n_segments, chunk):
+    """:func:`coo_matmul_T` through XLA's sorted ``segment_sum``."""
+    nnz = int(values.shape[0])
+    B = srcT.shape[-1]
+    dtype = jnp.result_type(srcT.dtype, values.dtype)
 
     def one_chunk(g, s, v):
         contrib = srcT[g, :] * v[:, None]  # (chunk, B) — already transposed
@@ -515,30 +570,79 @@ def coo_matmul_T(
             indices_are_sorted=True,
         )
 
-    n_chunks = -(-nnz // chunk)
-    if n_chunks == 1:
+    if nnz <= chunk:
         one = one_chunk(gather_idx, segment_idx, values)
         return one if acc is None else acc + one
-    pad = n_chunks * chunk - nnz
-    # padded slots: segment id == n_segments (dropped by segment_sum, and
-    # >= every real id so per-chunk sortedness holds) and value 0
-    g_p = jnp.concatenate([gather_idx, jnp.zeros((pad,), gather_idx.dtype)])
-    s_p = jnp.concatenate(
-        [segment_idx, jnp.full((pad,), n_segments, segment_idx.dtype)]
-    )
-    v_p = jnp.concatenate([values, jnp.zeros((pad,), values.dtype)])
-    slices = (
-        g_p.reshape(n_chunks, chunk),
-        s_p.reshape(n_chunks, chunk),
-        v_p.reshape(n_chunks, chunk),
-    )
 
     def body(a, sl):
         return a + one_chunk(*sl), None
 
     acc0 = jnp.zeros((n_segments, B), dtype) if acc is None else acc
+    slices = _chunked(
+        gather_idx, segment_idx, values, n_segments=n_segments, chunk=chunk
+    )
     out, _ = jax.lax.scan(body, acc0, slices)
     return out
+
+
+def _coo_matmul_T_pallas(
+    srcT, values, gather_idx, segment_idx, acc, *, n_segments, chunk,
+    interpret=False,
+):
+    """:func:`coo_matmul_T` through the one-hot MXU kernel: the XLA path's
+    chunks and gather, each chunk's gathered slab made lane-dense (B,
+    chunk) and padded to the kernel's slot multiple, weighted by the values
+    and reduced in the kernel into a (B, n_seg_pad) partial sum, and added
+    to the carry as the XLA path adds its chunks."""
+    nnz = int(values.shape[0])
+    B = srcT.shape[-1]
+    step = _sss.STEP_SLOTS
+
+    def reduce(xT, v, s):
+        pad = -(-s.shape[0] // step) * step - s.shape[0]
+        return _sss.sorted_segment_sum_T(
+            jnp.pad(xT, ((0, 0), (0, pad))),
+            jnp.pad(v, (0, pad)),
+            jnp.pad(s, (0, pad), constant_values=n_segments),
+            n_segments=n_segments, interpret=interpret,
+        )
+
+    if nnz <= chunk:
+        # the XLA path's row gather, which XLA shares with the dW pass's
+        # gather of the same rows
+        one = reduce(srcT[gather_idx, :].T, values, segment_idx)
+        one = one[:, :n_segments].T
+        return one if acc is None else acc + one
+
+    if B >= _sss.LANES:
+        # a gathered row fills the lanes: XLA's row gather, then a transpose
+        def gather(g):
+            return srcT[g, :].T
+    else:
+        # short rows: a column gather of the (B, src_dim) operand, which on
+        # a v5e takes leukemia's batch-5 dX 8.5 ms per step against 10.1,
+        # and its step 493 MB of temporaries against 519. At batch 512 it
+        # compiles to a gather that writes its slab one element at a time
+        # (leukemia's evaluation: 132 ms per epoch against 75).
+        src = srcT.T
+
+        def gather(g):
+            return src[:, g]
+
+    def body(a, sl):
+        g, s, v = sl
+        return a + reduce(gather(g), v, s), None
+
+    n_seg_pad = -(-n_segments // _sss.LANES) * _sss.LANES
+    if acc is None:
+        acc0 = jnp.zeros((B, n_seg_pad), jnp.float32)
+    else:
+        acc0 = jnp.pad(acc.T, ((0, 0), (0, n_seg_pad - n_segments)))
+    slices = _chunked(
+        gather_idx, segment_idx, values, n_segments=n_segments, chunk=chunk
+    )
+    out, _ = jax.lax.scan(body, acc0, slices)
+    return out[:, :n_segments].T
 
 
 def coo_dw(

@@ -16,7 +16,8 @@ Element granularity (the paper-faithful COO path) — dispatched by ``espmm``:
                 segment-sum passes (DESIGN.md §1 "Backward"): forward in
                 transposed (out_dim, batch) layout over the canonical
                 (col, row) order; dX over the row-sorted dual order (sorted
-                segment ids — no XLA scatter anywhere in the train step);
+                segment ids — no unsorted scatter in the train step; on the
+                TPU both segment sums are the one-hot MXU kernel);
                 dW as a chunked per-slot batch contraction. All three passes
                 peak at O(batch * chunk) intermediate memory.
 * ``segment`` — the same chunked forward with XLA-autodiff backward; never
@@ -197,8 +198,9 @@ def bsmm(
 #   dW   dv[j]            = sum_b x[b, rows[j]] * dy[b, cols[j]]
 #
 # Every pass is a chunked sorted-segment reduction (or contraction) in
-# transposed (features, batch) layout — no per-chunk transposes, no XLA
-# scatter, peak intermediate O(batch * chunk) for all three.
+# transposed (features, batch) layout — no per-chunk transposes, no unsorted
+# scatter, peak intermediate O(batch * chunk) for all three. fwd and dX share
+# ``coo_matmul_T``, whose segment sum is the one-hot MXU kernel on the TPU.
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))

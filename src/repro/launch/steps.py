@@ -6,7 +6,7 @@ reduced over the data axes by GSPMD from the in/out shardings. Sparse
 topology arrays ride along as non-trainable inputs — for the element (COO)
 path that now includes the dual-order views (``ElemTopoArrays``), so the
 whole step (forward AND the hand-derived custom-VJP backward) runs on the
-chunked segment-sum kernels with no XLA scatter anywhere.
+chunked sorted segment-sum passes with no unsorted scatter (DESIGN.md §1b).
 """
 from __future__ import annotations
 
