@@ -38,38 +38,60 @@ the median leaf's move by rounding alone and are left out of ``change`` and
 ``velocity``; every other leaf is read against its own norm, so a fault
 confined to a small leaf, such as a bias, is not diluted by the larger
 ones.
+
+The reference is the module that the configuration's ``reference`` names,
+a path from the checkout's root (``harness.resolve`` makes it absolute),
+loaded by path.
 """
 from __future__ import annotations
 
 import functools
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.references import set_mlp as ref
+from bench import harness
 
+ROOT = Path(__file__).resolve().parents[1]
 GRAD_FLOOR = 1e-3
-# elements of the reference forward's gathered (connections x rows) block
+# elements of the reference's gathered (connections x rows) slab per
+# connection block
 BLOCK_ELEMS = 1 << 26
+# ``reference_logits`` takes as many rows per call as keep every layer in
+# one connection block; where that is under ``WHOLE_ROWS_MIN`` rows, it takes
+# ``LOGIT_ROWS`` rows and cuts the layers into blocks. Over 3,000 rows at the
+# Table-4 widths on a TPU v5e, 512 rows a call took 7.0 s (64: 20.1 s, 256:
+# 7.3 s, 1,024: 9.2 s); all 3,000 in one call took 6.3 s but needs 13.1 GB
+# of temporaries beside the data
+WHOLE_ROWS_MIN = 64
+LOGIT_ROWS = 512
 NAMES = ("loss", "change", "velocity", "evolution", "nnz", "positions",
          "logits", "eval_acc")
 
 
+def load_reference(config):
+    """The reference module that ``config["reference"]`` names."""
+    return harness.load_module(ROOT / config["reference"])
+
+
 def reference_state(config, follow, dtype=jnp.float32, half=False, exchange=True):
     """Run the reference over the first epoch; host arrays out."""
+    ref = load_reference(config)
     params = jax.tree.map(jnp.asarray, follow["init"])
     topo = tuple((jnp.asarray(r), jnp.asarray(c)) for r, c in follow["topology"])
     if follow["job"] == "sequential":
         p, v, losses, g0 = ref.follow_sequential(
             config, params, topo, follow["x"], follow["y"], follow["perm"],
-            follow["key"], follow["lr"], dtype=dtype, half=half)
+            follow["key"], follow["lr"], dtype=dtype, half=half,
+            block_elems=BLOCK_ELEMS)
         loss = float(np.mean(np.asarray(losses, np.float64)))
     else:
         p, v, loss_sums, g0 = ref.follow_local_sgd(
             config, params, topo, follow["x"], follow["y"], follow["idx"],
             follow["lrs"], follow["valid"], follow["keys"], dtype=dtype,
-            half=half, exchange=exchange)
+            half=half, exchange=exchange, block_elems=BLOCK_ELEMS)
         k = follow["idx"].shape[1]
         loss = float(np.sum(np.asarray(loss_sums, np.float64))) / (
             k * float(follow["valid"].sum()))
@@ -181,16 +203,26 @@ def readings(state, refst, init):
     return {k: (v if np.isfinite(v) else float("inf")) for k, v in out.items()}
 
 
+def logit_rows(max_nnz, n):
+    """Rows per call of the reference's forward over ``n`` rows whose widest
+    layer has ``max_nnz`` connections: as many as keep that layer's slab in
+    one block of ``BLOCK_ELEMS`` elements, or ``LOGIT_ROWS`` where that is
+    under ``WHOLE_ROWS_MIN``."""
+    rows = BLOCK_ELEMS // max_nnz
+    return min(n, rows if rows >= WHOLE_ROWS_MIN else LOGIT_ROWS)
+
+
 def reference_logits(config, params, topology, x, dtype=jnp.float32):
     """The reference's forward with dropout off over the rows of ``x``, in
-    equal blocks that keep its gathered (connections x rows) block under
-    ``BLOCK_ELEMS`` elements; host array out."""
+    equal calls of :func:`logit_rows` rows; host array out."""
+    ref = load_reference(config)
     params = jax.tree.map(jnp.asarray, params)
     topo = tuple((jnp.asarray(r), jnp.asarray(c)) for r, c in topology)
     n = x.shape[0]
-    rows = min(n, max(1, BLOCK_ELEMS // max(r.size for r, _ in topology)))
+    rows = logit_rows(max(r.size for r, _ in topology), n)
     fwd = jax.jit(functools.partial(ref.forward, cfg=config, key=None,
-                                    dtype=jnp.dtype(dtype)))
+                                    dtype=jnp.dtype(dtype),
+                                    block_elems=BLOCK_ELEMS))
     out = []
     for s in range(0, n, rows):
         xb = x[s:s + rows]

@@ -14,6 +14,7 @@ sits in a file of its own, found by the name ``BENCHMARK.json`` gives:
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import math
@@ -43,13 +44,16 @@ def resolve(spec: dict, workload: str, bench: Path = BENCH) -> dict:
     cell = cells[workload]
     conf = {c["name"]: c for c in spec["configs"]}[cell["config"]]
     root = bench.parent
+    config = json.loads((root / conf["file"]).read_text())
+    # the configuration's reference, found in the checkout that holds the cell
+    config["reference"] = str(root / config["reference"])
 
     def applies(metric):
         return workload in metric.get("workloads", [workload])
 
     return {
         "cell": cell,
-        "config": json.loads((root / conf["file"]).read_text()),
+        "config": config,
         "traffic": json.loads((bench / "traffic" / f"{cell['traffic']}.json").read_text()),
         "limits": json.loads((bench / "limits" / f"{workload}.json").read_text()),
         "end_to_end": [m for m in spec["end_to_end"] if applies(m)],
@@ -59,11 +63,18 @@ def resolve(spec: dict, workload: str, bench: Path = BENCH) -> dict:
     }
 
 
-def load_reader(path: Path):
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{path.stem}", path)
+@functools.cache
+def load_module(path: Path):
+    """The Python file at ``path``, loaded by path once per process."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{path.parent.name}_{path.stem}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(path: Path):
+    return load_module(path).read
 
 
 class MetricContext:

@@ -43,7 +43,10 @@ def test_spec_keeps_to_the_contract():
     for c in SPEC["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert c["file"].startswith("bench/") and (ROOT / c["file"]).is_file()
-        assert json.loads((ROOT / c["file"]).read_text())["reduced"] == c["reduced"]
+        config = json.loads((ROOT / c["file"]).read_text())
+        assert config["reduced"] == c["reduced"]
+        assert config["reference"].startswith("bench/")
+        assert (ROOT / config["reference"]).is_file()
     for w in SPEC["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200
